@@ -19,6 +19,12 @@ Structure per interaction layer t:
 Total energy  E = sum_i (E0_{z_i} + sum_t readout_t(h_i^t));
 forces  F = -dE/dr  via jax.grad (tests check rotational equivariance).
 
+Each part runs under a ``jax.named_scope`` that lands in its HLO ops'
+metadata: ``edge_geometry``, ``embedding``, ``radial``, ``interaction``,
+``linear``, ``symcon``, ``skip``, ``readout`` and ``loss``.  Differentiation
+wraps the scope in its transforms (``transpose(jvp(symcon))``), so a trace
+reader can time each part in each pass whatever implements it.
+
 Batch layout (static shapes; padding masked):
   species    [N] int32   (padded entries arbitrary, masked by node_mask)
   positions  [N, 3]
@@ -269,15 +275,17 @@ def mace_energy(
     N = species.shape[0]
     k = cfg.channels
 
-    vec = positions[receivers] - positions[senders]          # [E, 3]
-    lengths = jnp.sqrt(jnp.sum(vec * vec, axis=-1) + 1e-18)
-    Y = spherical_harmonics(cfg.sh_lmax, vec).astype(dt)     # [E, dim_sh]
-    radial = radial_embedding(lengths, cfg.r_max, cfg.num_bessel).astype(dt)
+    with jax.named_scope("edge_geometry"):
+        vec = positions[receivers] - positions[senders]      # [E, 3]
+        lengths = jnp.sqrt(jnp.sum(vec * vec, axis=-1) + 1e-18)
+        Y = spherical_harmonics(cfg.sh_lmax, vec).astype(dt)  # [E, dim_sh]
+        radial = radial_embedding(lengths, cfg.r_max, cfg.num_bessel).astype(dt)
 
     # initial node features: species embedding, l=0 block
-    h = params["embed"][species][:, :, None]                 # [N, k, 1]
-    nmask = node_mask.astype(dt)[:, None, None]
-    h = h * nmask
+    with jax.named_scope("embedding"):
+        h = params["embed"][species][:, :, None]             # [N, k, 1]
+        nmask = node_mask.astype(dt)[:, None, None]
+        h = h * nmask
 
     site_energy = jnp.zeros((N,), dt)
 
@@ -292,37 +300,46 @@ def mace_energy(
         )
         sc_fn = resolve("symcon", cfg.symcon_impl_name, cfg.symcon_spec())
 
-        h_up = _apply_linear_per_l(layer["lin_up"], h, h_spec)
-        R = apply_mlp(layer["radial"], radial).reshape(-1, tp_spec.n_paths, k)
+        with jax.named_scope("linear"):
+            h_up = _apply_linear_per_l(layer["lin_up"], h, h_spec)
+        with jax.named_scope("radial"):
+            R = apply_mlp(layer["radial"], radial).reshape(-1, tp_spec.n_paths, k)
         # interaction op: TP (Algorithm 2) + masked scatter to receivers
         # + /avg_num_neighbors, fused behind one registry-resolved call
-        A = int_fn(Y, h_up, R, senders, receivers, edge_mask,
-                   blocking=blocking)                        # [N, k, dim_a]
-        A = _apply_linear_per_l(layer["lin_a"], A, cfg.a_spec)
+        with jax.named_scope("interaction"):
+            A = int_fn(Y, h_up, R, senders, receivers, edge_mask,
+                       blocking=blocking)                    # [N, k, dim_a]
+        with jax.named_scope("linear"):
+            A = _apply_linear_per_l(layer["lin_a"], A, cfg.a_spec)
 
-        B = sc_fn(A, species, layer["symcon"])               # [N, k, dim_hidden]
-        m = _apply_linear_per_l(layer["lin_msg"], B, cfg.hidden_spec)
+        with jax.named_scope("symcon"):
+            B = sc_fn(A, species, layer["symcon"])           # [N, k, dim_hidden]
+        with jax.named_scope("linear"):
+            m = _apply_linear_per_l(layer["lin_msg"], B, cfg.hidden_spec)
 
         # species-dependent skip (residual) from the *old* h
-        skip = jnp.zeros_like(m)
-        for i, (l, sl_h) in enumerate(h_spec.slices()):
-            if l in cfg.hidden_spec.ls:
-                W = layer["skip"][f"l{l}_{i}"][species]      # [N, k, k]
-                sl_o = cfg.hidden_spec.slice_for(l)
-                skip = skip.at[:, :, sl_o].add(
-                    jnp.einsum("nkd,nkq->nqd", h[:, :, sl_h], W)
-                )
-        h = (m + skip) * nmask
+        with jax.named_scope("skip"):
+            skip = jnp.zeros_like(m)
+            for i, (l, sl_h) in enumerate(h_spec.slices()):
+                if l in cfg.hidden_spec.ls:
+                    W = layer["skip"][f"l{l}_{i}"][species]  # [N, k, k]
+                    sl_o = cfg.hidden_spec.slice_for(l)
+                    skip = skip.at[:, :, sl_o].add(
+                        jnp.einsum("nkd,nkq->nqd", h[:, :, sl_h], W)
+                    )
+            h = (m + skip) * nmask
 
-        inv = h[:, :, cfg.hidden_spec.slice_for(0)][:, :, 0]  # [N, k]
-        if t < cfg.n_interactions - 1:
-            e_t = (inv @ layer["readout"])[:, 0]
-        else:
-            e_t = apply_mlp(layer["readout_mlp"], inv)[:, 0]
-        site_energy = site_energy + e_t * node_mask.astype(dt)
+        with jax.named_scope("readout"):
+            inv = h[:, :, cfg.hidden_spec.slice_for(0)][:, :, 0]  # [N, k]
+            if t < cfg.n_interactions - 1:
+                e_t = (inv @ layer["readout"])[:, 0]
+            else:
+                e_t = apply_mlp(layer["readout_mlp"], inv)[:, 0]
+            site_energy = site_energy + e_t * node_mask.astype(dt)
 
-    site_energy = site_energy + params["e0"][species] * node_mask.astype(dt)
-    return jax.ops.segment_sum(site_energy, graph_id, n_graphs)
+    with jax.named_scope("readout"):
+        site_energy = site_energy + params["e0"][species] * node_mask.astype(dt)
+        return jax.ops.segment_sum(site_energy, graph_id, n_graphs)
 
 
 def mace_energy_forces(
@@ -354,7 +371,8 @@ def mace_energy_forces(
         return jnp.sum(e), e
 
     grad, energy = jax.grad(e_total, has_aux=True)(batch["positions"])
-    forces = -grad * batch["node_mask"].astype(grad.dtype)[:, None]
+    with jax.named_scope("loss"):
+        forces = -grad * batch["node_mask"].astype(grad.dtype)[:, None]
     return energy, forces
 
 
@@ -368,22 +386,23 @@ def weighted_loss(
 ):
     """Paper §5.2's weighted (energy, forces) loss."""
     energy, forces = mace_energy_forces(params, cfg, batch, n_graphs)
-    nat = jnp.maximum(
-        jax.ops.segment_sum(batch["node_mask"].astype(energy.dtype), batch["graph_id"], n_graphs),
-        1.0,
-    )
-    gmask = (nat > 0.5).astype(energy.dtype)
-    e_err = ((energy - batch["energy"]) / nat) ** 2 * gmask
-    f_err = jnp.sum(
-        (forces - batch["forces"]) ** 2, axis=-1
-    ) * batch["node_mask"].astype(energy.dtype)
-    n_g = jnp.maximum(jnp.sum(gmask), 1.0)
-    n_at = jnp.maximum(jnp.sum(batch["node_mask"].astype(energy.dtype)), 1.0)
-    loss = energy_weight * jnp.sum(e_err) / n_g + forces_weight * jnp.sum(f_err) / (
-        3.0 * n_at
-    )
-    return loss, {"loss": loss, "e_rmse": jnp.sqrt(jnp.sum(e_err) / n_g),
-                  "f_rmse": jnp.sqrt(jnp.sum(f_err) / (3.0 * n_at))}
+    with jax.named_scope("loss"):
+        nat = jnp.maximum(
+            jax.ops.segment_sum(batch["node_mask"].astype(energy.dtype),
+                                batch["graph_id"], n_graphs),
+            1.0,
+        )
+        gmask = (nat > 0.5).astype(energy.dtype)
+        e_err = ((energy - batch["energy"]) / nat) ** 2 * gmask
+        f_err = jnp.sum(
+            (forces - batch["forces"]) ** 2, axis=-1
+        ) * batch["node_mask"].astype(energy.dtype)
+        n_g = jnp.maximum(jnp.sum(gmask), 1.0)
+        n_at = jnp.maximum(jnp.sum(batch["node_mask"].astype(energy.dtype)), 1.0)
+        loss = energy_weight * jnp.sum(e_err) / n_g + forces_weight * jnp.sum(
+            f_err) / (3.0 * n_at)
+        return loss, {"loss": loss, "e_rmse": jnp.sqrt(jnp.sum(e_err) / n_g),
+                      "f_rmse": jnp.sqrt(jnp.sum(f_err) / (3.0 * n_at))}
 
 
 def param_count(params: Params) -> int:

@@ -15,10 +15,11 @@ recompiles bounded and per-rank blockings stackable to ``[R, ...]``.
 from __future__ import annotations
 
 import dataclasses
-import time
-from typing import Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
+
+from repro.tracing import span
 
 from .blocking import (
     DEFAULT_BLOCK_E,
@@ -82,13 +83,15 @@ def bin_blocking_arrays(
 
 def collate_bin(
     mols: Sequence[Molecule], shape: BinShape, *, strict: bool = False,
-    with_blocking: bool = False, timings: Optional[Dict[str, float]] = None,
+    with_blocking: bool = False, stats: Optional[Dict[str, Any]] = None,
 ) -> Dict[str, np.ndarray]:
     """Concatenate graphs block-diagonally (Fig. 3) and pad to ``shape``.
 
-    ``timings`` (optional, mutated) accumulates the host seconds spent on
-    edge blocking under ``"block_s"`` so callers (the engines) can
-    attribute the fused-interaction preprocessing in telemetry."""
+    ``stats`` (optional, mutated) accumulates the host seconds spent on
+    edge blocking under ``"block_s"`` (the ``data.blocking`` span), and
+    appends the bin's real atoms, as collated, to the list under
+    ``"atoms"``: the host counter the sequential engine's loads and the
+    step span report."""
     N, E, G = shape.max_nodes, shape.max_edges, shape.max_graphs
     n_tot = sum(m.n_atoms for m in mols)
     e_tot = sum(m.n_edges for m in mols)
@@ -145,12 +148,12 @@ def collate_bin(
         "forces": forces,
     }
     if with_blocking:
-        t0 = time.perf_counter()
-        out.update(bin_blocking_arrays(out, shape))
-        if timings is not None:
-            timings["block_s"] = (
-                timings.get("block_s", 0.0) + time.perf_counter() - t0
-            )
+        with span("data.blocking") as blocked:
+            out.update(bin_blocking_arrays(out, shape))
+        if stats is not None:
+            stats["block_s"] = stats.get("block_s", 0.0) + blocked.seconds
+    if stats is not None:
+        stats.setdefault("atoms", []).append(n_off)
     return out
 
 
@@ -160,7 +163,7 @@ def collate_stacked(
     *,
     strict: bool = False,
     with_blocking: bool = False,
-    timings: Optional[Dict[str, float]] = None,
+    stats: Optional[Dict[str, Any]] = None,
 ) -> Dict[str, np.ndarray]:
     """Collate R per-rank bins and stack them on a leading ``[R, ...]`` axis.
 
@@ -174,7 +177,7 @@ def collate_stacked(
         raise ValueError("need at least one rank's bin")
     cols = [
         collate_bin(m, shape, strict=strict, with_blocking=with_blocking,
-                    timings=timings)
+                    stats=stats)
         for m in mols_per_rank
     ]
     return {k: np.stack([c[k] for c in cols]) for k in cols[0]}

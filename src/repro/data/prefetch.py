@@ -39,11 +39,13 @@ is that overlap as a first-class subsystem:
   :meth:`raise_pending` so a real collate failure can never be masked by
   the shutdown path.
 * **Telemetry** — every yielded :class:`PrefetchItem` carries ``collate_s``
-  (host wall seconds spent building the batch) and ``wait_s`` (seconds the
-  consumer blocked waiting for it).  ``overlap_s = max(collate_s - wait_s,
-  0)`` is the collate work actually hidden behind device compute; the
-  trainer folds these into ``RankTelemetry`` (``record_host``) so benchmarks
-  report measured host/device overlap next to the straggler model.
+  (host wall seconds spent building the batch, the ``data.fetch`` span) and
+  ``wait_s`` (seconds the consumer blocked waiting for it, the
+  ``train.wait_batch`` span; ``repro.tracing``).  ``overlap_s =
+  max(collate_s - wait_s, 0)`` is the collate work hidden behind device
+  compute; the trainer folds these into ``RankTelemetry``
+  (``record_host``) so benchmarks report measured host/device overlap next
+  to the straggler model.
 """
 from __future__ import annotations
 
@@ -54,6 +56,8 @@ import threading
 import time
 import weakref
 from typing import Any, Callable, Iterable, Iterator, Optional
+
+from repro.tracing import span
 
 __all__ = ["PrefetchItem", "PrefetchPipeline", "ProducerStalled"]
 
@@ -118,11 +122,10 @@ def _produce(items: Iterator[Any], fetch: Callable[[Any], Any],
             if stop.is_set():
                 return
             progress.update(state="fetch", index=i, t=time.monotonic())
-            t0 = time.perf_counter()
-            batch = fetch(item)
-            dt = time.perf_counter() - t0
+            with span("data.fetch") as fetched:
+                batch = fetch(item)
             progress.update(state="idle", index=i, t=time.monotonic())
-            if not put(PrefetchItem(i, item, batch, dt, 0.0)):
+            if not put(PrefetchItem(i, item, batch, fetched.seconds, 0.0)):
                 return
     except BaseException as exc:  # propagate into the consumer
         progress.update(state="idle", t=time.monotonic())
@@ -224,21 +227,21 @@ class PrefetchPipeline:
             except StopIteration:
                 self.close()
                 raise
-            t0 = time.perf_counter()
             try:
-                batch = self._fetch(item)
+                # the loop waits exactly as long as the fetch runs
+                with span("train.wait_batch"), span("data.fetch") as fetched:
+                    batch = self._fetch(item)
             except StopIteration as exc:
                 # PEP-479 style: never let a leaked StopIteration masquerade
                 # as a normal end of the epoch stream
                 self.close()
                 raise RuntimeError("prefetch fetch raised StopIteration") from exc
-            dt = time.perf_counter() - t0
+            dt = fetched.seconds
             out = PrefetchItem(self._index, item, batch, dt, dt)
             self._index += 1
             return out
-        t0 = time.perf_counter()
-        payload = self._queue.get()
-        wait = time.perf_counter() - t0
+        with span("train.wait_batch") as waited:
+            payload = self._queue.get()
         if payload is _END:
             self.close()
             raise StopIteration
@@ -254,7 +257,7 @@ class PrefetchPipeline:
                     "prefetch fetch raised StopIteration"
                 ) from payload
             raise payload
-        payload.wait_s = wait
+        payload.wait_s = waited.seconds
         return payload
 
     # ----------------------------- lifecycle ------------------------------

@@ -170,7 +170,7 @@ class ServeEngine:
         stats = {"block_s": 0.0}
         col = collate_bin(
             mols, bucket, strict=self.strict_collate,
-            with_blocking=self.with_blocking, timings=stats,
+            with_blocking=self.with_blocking, stats=stats,
         )
         return {k: jnp.asarray(v) for k, v in col.items()}, stats
 

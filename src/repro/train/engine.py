@@ -7,7 +7,8 @@ one bin per GPU per step -> gradient all-reduce).  Everything above the
 optimizer update is therefore factored into an *engine* with one contract:
 
     engine.collate(mols_per_rank, bin_shape)
-                       -> (backend batch layout, host stats {"block_s": s})
+                       -> (backend batch layout, host stats: "block_s"
+                           and, optionally, per-rank real "atoms")
     engine.init_ef(params)                   -> error-feedback residuals
     engine.step(params, opt_state, ef, batch, i)
                                     -> (params, opt_state, ef, metrics)
@@ -113,8 +114,10 @@ backends).
 Telemetry
 ---------
 Each engine records a ``RankTelemetry``: per-step per-rank wall seconds
-(sequential; shard_map reports the lock-step wall time) and per-rank loads
-(real atoms per bin).  Telemetry is per engine *generation*: an elastic
+(sequential; shard_map reports the lock-step wall time), the durations of
+the engine's own host spans (``repro.tracing``), and per-rank loads (real
+atoms per bin: collation's host counter in the sequential oracle, the
+step's own device count in the SPMD engines).  Telemetry is per engine *generation*: an elastic
 rescale closes the engine and its telemetry with it, so the trainer keeps
 the closed generations and ``RankTelemetry.merged(*generations)``
 (``Trainer.telemetry``) provides the whole-run view — ``bench_scaling
@@ -130,7 +133,6 @@ hidden behind device compute.
 from __future__ import annotations
 
 import dataclasses
-import time
 from functools import partial
 from typing import Any, Callable, Dict, List, Sequence, Tuple
 
@@ -145,6 +147,7 @@ from repro.core.mace import MaceConfig, weighted_loss
 from repro.data.collate import BinShape, collate_bin, collate_stacked
 from repro.kernels import registry
 from repro.launch.mesh import make_dp_mesh, make_node_device_mesh
+from repro.tracing import span
 from .compression import compressed_psum_ef
 from .optimizer import Transform, apply_updates
 
@@ -343,9 +346,6 @@ class MergedTelemetry:
         """One [steps, ranks] wall-seconds matrix per generation."""
         return [g.work_matrix(skip) for g in self.generations]
 
-    def load_matrices(self, skip: int = 0) -> List[np.ndarray]:
-        return [g.load_matrix(skip) for g in self.generations]
-
     def straggler_matrices(self, skip: int = 0) -> List[np.ndarray]:
         """Per-generation straggler work (feed the *matching-rank-count*
         matrix to ``binpack.balance_metrics(measured_work=...)``)."""
@@ -473,8 +473,25 @@ def _init_stacked_ef(params, n_ranks: int, compress: bool):
     )
 
 
+class RankBatches(list):
+    """One step's per-rank device batches (the sequential oracle's batch
+    layout), with each bin's real atoms as collation counted them on the
+    host."""
+
+    def __init__(self, batches: Sequence[Batch], loads: Sequence[int]):
+        super().__init__(batches)
+        self.loads = [float(n) for n in loads]
+
+
 def _rank_load(batch: Batch) -> jnp.ndarray:
     return jnp.sum(batch["node_mask"].astype(jnp.float32))
+
+
+def _optimizer_step(optimizer: Transform, grads, opt_state, params, step_idx):
+    """(new params, new optimizer state), under the ``optimizer`` scope."""
+    with jax.named_scope("optimizer"):
+        updates, opt_state = optimizer.update(grads, opt_state, params, step_idx)
+        return apply_updates(params, updates), opt_state
 
 
 def interaction_consumes_blocking(mace_cfg: MaceConfig) -> bool:
@@ -524,21 +541,23 @@ class SequentialEngine:
 
         @jax.jit
         def finalize(params, opt_state, ef, stacked_grads, stacked_metrics, step_idx):
-            if compress:
-                reduce_ef = (
-                    partial(_emulated_hier_compressed_mean, n_nodes=n_nodes)
-                    if n_nodes
-                    else _emulated_compressed_mean_ef
-                )
-                pairs = jax.tree.map(reduce_ef, stacked_grads, ef)
-                is_pair = lambda x: isinstance(x, tuple)
-                grads = jax.tree.map(lambda x: x[0], pairs, is_leaf=is_pair)
-                ef = jax.tree.map(lambda x: x[1], pairs, is_leaf=is_pair)
-            else:
-                grads = jax.tree.map(partial(jnp.mean, axis=0), stacked_grads)
-            metrics = jax.tree.map(partial(jnp.mean, axis=0), stacked_metrics)
-            updates, opt_state = optimizer.update(grads, opt_state, params, step_idx)
-            return apply_updates(params, updates), opt_state, ef, metrics
+            # the host-loop twin of the SPMD engines' all-reduce
+            with jax.named_scope("allreduce"):
+                if compress:
+                    reduce_ef = (
+                        partial(_emulated_hier_compressed_mean, n_nodes=n_nodes)
+                        if n_nodes
+                        else _emulated_compressed_mean_ef
+                    )
+                    pairs = jax.tree.map(reduce_ef, stacked_grads, ef)
+                    is_pair = lambda x: isinstance(x, tuple)
+                    grads = jax.tree.map(lambda x: x[0], pairs, is_leaf=is_pair)
+                    ef = jax.tree.map(lambda x: x[1], pairs, is_leaf=is_pair)
+                else:
+                    grads = jax.tree.map(partial(jnp.mean, axis=0), stacked_grads)
+                metrics = jax.tree.map(partial(jnp.mean, axis=0), stacked_metrics)
+            return (*_optimizer_step(optimizer, grads, opt_state, params,
+                                     step_idx), ef, metrics)
 
         self._finalize = finalize
 
@@ -592,32 +611,37 @@ class SequentialEngine:
         self, mols_per_rank: Sequence[Sequence[Any]], shape: BinShape
     ):
         stats = {"block_s": 0.0}
-        cols = [
-            collate_bin(m, shape, with_blocking=self.with_blocking,
-                        timings=stats)
-            for m in mols_per_rank
-        ]
-        batches = [{k: jnp.asarray(v) for k, v in c.items()} for c in cols]
-        return batches, stats
+        with span("data.collate"):
+            cols = [
+                collate_bin(m, shape, with_blocking=self.with_blocking,
+                            stats=stats)
+                for m in mols_per_rank
+            ]
+        with span("data.to_device"):
+            batches = [{k: jnp.asarray(v) for k, v in c.items()} for c in cols]
+        return RankBatches(batches, stats["atoms"]), stats
 
-    def step(self, params, opt_state, ef_state, batches: List[Batch], step_idx):
+    def step(self, params, opt_state, ef_state, batches: RankBatches, step_idx):
         if self.closed:
             raise RuntimeError("engine is closed (rescaled away?)")
-        grads_l, metrics_l, times, loads = [], [], [], []
-        for b in batches:
-            t0 = time.perf_counter()
-            (_, metrics), grads = self._grad_fn(params, b)
-            jax.block_until_ready(grads)
-            times.append(time.perf_counter() - t0)
-            loads.append(float(_rank_load(b)))
-            grads_l.append(grads)
-            metrics_l.append(metrics)
-        stacked_g = jax.tree.map(lambda *g: jnp.stack(g), *grads_l)
-        stacked_m = jax.tree.map(lambda *m: jnp.stack(m), *metrics_l)
-        params, opt_state, ef_state, metrics = self._finalize(
-            params, opt_state, ef_state, stacked_g, stacked_m, step_idx
-        )
-        self.telemetry.record(times, loads)
+        grads_l, metrics_l, times = [], [], []
+        with span("engine.step"):
+            for b in batches:
+                with span("engine.grad") as dispatch:
+                    (_, metrics), grads = self._grad_fn(params, b)
+                with span("engine.grad_wait") as wait:
+                    jax.block_until_ready(grads)
+                times.append(dispatch.seconds + wait.seconds)
+                grads_l.append(grads)
+                metrics_l.append(metrics)
+            with span("engine.stack"):
+                stacked_g = jax.tree.map(lambda *g: jnp.stack(g), *grads_l)
+                stacked_m = jax.tree.map(lambda *m: jnp.stack(m), *metrics_l)
+            with span("engine.finalize"):
+                params, opt_state, ef_state, metrics = self._finalize(
+                    params, opt_state, ef_state, stacked_g, stacked_m, step_idx
+                )
+        self.telemetry.record(times, batches.loads)
         return params, opt_state, ef_state, metrics
 
 
@@ -660,19 +684,21 @@ class ShardMapEngine:
             (_, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                 params, batch
             )
-            if compress:
-                pairs = jax.tree.map(
-                    lambda g, e: compressed_psum_ef(g, e[0], DP_AXIS), grads, ef
-                )
-                is_pair = lambda x: isinstance(x, tuple)
-                grads = jax.tree.map(lambda x: x[0], pairs, is_leaf=is_pair)
-                ef = jax.tree.map(lambda x: x[1][None], pairs, is_leaf=is_pair)
-            else:
-                grads = jax.lax.pmean(grads, DP_AXIS)
-            metrics = jax.lax.pmean(metrics, DP_AXIS)
+            with jax.named_scope("allreduce"):
+                if compress:
+                    pairs = jax.tree.map(
+                        lambda g, e: compressed_psum_ef(g, e[0], DP_AXIS),
+                        grads, ef,
+                    )
+                    is_pair = lambda x: isinstance(x, tuple)
+                    grads = jax.tree.map(lambda x: x[0], pairs, is_leaf=is_pair)
+                    ef = jax.tree.map(lambda x: x[1][None], pairs, is_leaf=is_pair)
+                else:
+                    grads = jax.lax.pmean(grads, DP_AXIS)
+                metrics = jax.lax.pmean(metrics, DP_AXIS)
             load = _rank_load(batch)[None]               # [1] -> gathers to [R]
-            updates, opt_state = optimizer.update(grads, opt_state, params, step_idx)
-            return apply_updates(params, updates), opt_state, ef, metrics, load
+            return (*_optimizer_step(optimizer, grads, opt_state, params,
+                                     step_idx), ef, metrics, load)
 
         # check_vma=False: under the varying-manual-axes check, jax.grad
         # psums the cotangent of every replicated param itself (the
@@ -737,26 +763,32 @@ class ShardMapEngine:
                 f"got {len(mols_per_rank)} bins for {self.n_ranks} ranks"
             )
         stats = {"block_s": 0.0}
-        arrs = collate_stacked(
-            mols_per_rank, shape, with_blocking=self.with_blocking,
-            timings=stats,
-        )
-        return {k: jnp.asarray(v) for k, v in arrs.items()}, stats
+        with span("data.collate"):
+            arrs = collate_stacked(
+                mols_per_rank, shape, with_blocking=self.with_blocking,
+                stats=stats,
+            )
+        # each rank's bin goes straight to its own device
+        with span("data.to_device"):
+            batch = jax.device_put(
+                arrs, jax.sharding.NamedSharding(self.mesh, P(DP_AXIS))
+            )
+        return batch, stats
 
     def step(self, params, opt_state, ef_state, batch: Batch, step_idx):
         if self.closed:
             raise RuntimeError("engine is closed (rescaled away?)")
-        t0 = time.perf_counter()
-        params, opt_state, ef_state, metrics, loads = self._step_fn(
-            params, opt_state, ef_state, batch, step_idx
-        )
-        jax.block_until_ready(params)
-        wall = time.perf_counter() - t0
+        with span("engine.step") as whole:
+            params, opt_state, ef_state, metrics, loads = self._step_fn(
+                params, opt_state, ef_state, batch, step_idx
+            )
+            with span("engine.wait"):
+                jax.block_until_ready(params)
         # lock-step SPMD: per-rank wall time is indistinguishable on one
         # host, so each rank is charged the step wall; loads stay per-rank
         # (telemetry is marked lockstep so straggler_matrix uses loads).
         self.telemetry.record(
-            [wall] * self.n_ranks, [float(x) for x in np.asarray(loads)]
+            [whole.seconds] * self.n_ranks, [float(x) for x in np.asarray(loads)]
         )
         return params, opt_state, ef_state, metrics
 
@@ -826,32 +858,35 @@ class MultiHostEngine:
             (_, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                 params, batch
             )
-            # level 1: intra-node mean over the fast ("device") links —
-            # cheap bandwidth, so no quantisation here
-            grads = jax.lax.pmean(grads, DEVICE_AXIS)
-            if compress:
-                # level 2: int8-EF across nodes only, where bandwidth is
-                # scarce; residual is per-node (identical on every device
-                # of a row, since the inputs are post-pmean)
-                pairs = jax.tree.map(
-                    lambda g, e: compressed_psum_ef(
-                        g, e[0], NODE_AXIS, axis_size=n_nodes
-                    ),
-                    grads, ef,
+            with jax.named_scope("allreduce"):
+                # level 1: intra-node mean over the fast ("device") links —
+                # cheap bandwidth, so no quantisation here
+                grads = jax.lax.pmean(grads, DEVICE_AXIS)
+                if compress:
+                    # level 2: int8-EF across nodes only, where bandwidth is
+                    # scarce; residual is per-node (identical on every device
+                    # of a row, since the inputs are post-pmean)
+                    pairs = jax.tree.map(
+                        lambda g, e: compressed_psum_ef(
+                            g, e[0], NODE_AXIS, axis_size=n_nodes
+                        ),
+                        grads, ef,
+                    )
+                    is_pair = lambda x: isinstance(x, tuple)
+                    grads = jax.tree.map(lambda x: x[0], pairs, is_leaf=is_pair)
+                    ef = jax.tree.map(lambda x: x[1][None], pairs, is_leaf=is_pair)
+                else:
+                    grads = jax.lax.pmean(grads, NODE_AXIS)
+                metrics = jax.lax.pmean(metrics, (NODE_AXIS, DEVICE_AXIS))
+                # node-major [R] of per-rank loads, replicated so the host
+                # can read it from any process (telemetry feeds
+                # two_level_metrics)
+                loads = jax.lax.all_gather(
+                    _rank_load(batch)[None], (NODE_AXIS, DEVICE_AXIS),
+                    tiled=True,
                 )
-                is_pair = lambda x: isinstance(x, tuple)
-                grads = jax.tree.map(lambda x: x[0], pairs, is_leaf=is_pair)
-                ef = jax.tree.map(lambda x: x[1][None], pairs, is_leaf=is_pair)
-            else:
-                grads = jax.lax.pmean(grads, NODE_AXIS)
-            metrics = jax.lax.pmean(metrics, (NODE_AXIS, DEVICE_AXIS))
-            # node-major [R] of per-rank loads, replicated so the host can
-            # read it from any process (telemetry feeds two_level_metrics)
-            loads = jax.lax.all_gather(
-                _rank_load(batch)[None], (NODE_AXIS, DEVICE_AXIS), tiled=True
-            )
-            updates, opt_state = optimizer.update(grads, opt_state, params, step_idx)
-            return apply_updates(params, updates), opt_state, ef, metrics, loads
+            return (*_optimizer_step(optimizer, grads, opt_state, params,
+                                     step_idx), ef, metrics, loads)
 
         # check_vma=False for the reasons given in ShardMapEngine
         self._step_fn = jax.jit(
@@ -983,43 +1018,43 @@ class MultiHostEngine:
                 f"got {len(mols_per_rank)} bins for {self.n_ranks} ranks"
             )
         stats = {"block_s": 0.0}
+        local = mols_per_rank
         if self.process_count > 1:
             # each process collates only its own node's bins (node-major
             # rank order: rank r -> node r // dpn) and contributes them as
             # the local shard of the global [R, ...] batch
             lo = self.process_index * self.devices_per_node
             local = mols_per_rank[lo:lo + self.devices_per_node]
+        with span("data.collate"):
             arrs = collate_stacked(
-                local, shape, with_blocking=self.with_blocking, timings=stats
+                local, shape, with_blocking=self.with_blocking, stats=stats
             )
-            sh = jax.sharding.NamedSharding(
-                self.mesh, P((NODE_AXIS, DEVICE_AXIS))
-            )
-            batch = {
-                k: jax.make_array_from_process_local_data(
-                    sh, v, (self.n_ranks,) + v.shape[1:]
+        with span("data.to_device"):
+            if self.process_count > 1:
+                sh = jax.sharding.NamedSharding(
+                    self.mesh, P((NODE_AXIS, DEVICE_AXIS))
                 )
-                for k, v in arrs.items()
-            }
-        else:
-            arrs = collate_stacked(
-                mols_per_rank, shape, with_blocking=self.with_blocking,
-                timings=stats,
-            )
-            batch = {k: jnp.asarray(v) for k, v in arrs.items()}
+                batch = {
+                    k: jax.make_array_from_process_local_data(
+                        sh, v, (self.n_ranks,) + v.shape[1:]
+                    )
+                    for k, v in arrs.items()
+                }
+            else:
+                batch = {k: jnp.asarray(v) for k, v in arrs.items()}
         return batch, stats
 
     def step(self, params, opt_state, ef_state, batch: Batch, step_idx):
         if self.closed:
             raise RuntimeError("engine is closed (rescaled away?)")
-        t0 = time.perf_counter()
-        params, opt_state, ef_state, metrics, loads = self._step_fn(
-            params, opt_state, ef_state, batch, step_idx
-        )
-        jax.block_until_ready(params)
-        wall = time.perf_counter() - t0
+        with span("engine.step") as whole:
+            params, opt_state, ef_state, metrics, loads = self._step_fn(
+                params, opt_state, ef_state, batch, step_idx
+            )
+            with span("engine.wait"):
+                jax.block_until_ready(params)
         self.telemetry.record(
-            [wall] * self.n_ranks, [float(x) for x in np.asarray(loads)]
+            [whole.seconds] * self.n_ranks, [float(x) for x in np.asarray(loads)]
         )
         return params, opt_state, ef_state, metrics
 

@@ -11,7 +11,11 @@ checkpoints, and resume (params, opt state, EMA, sampler cursor all
 restored).  ``simulate_failure_at`` lets tests kill the loop mid-epoch and
 prove restart equivalence.  Per-rank step-time/load telemetry plus per-step
 host collate/wait times are exposed via ``Trainer.engine.telemetry`` for the
-straggler model and the host/device overlap report.
+straggler model and the host/device overlap report.  Each step is a
+``train.step`` span (``repro.tracing``); while a profiler runs its
+arguments are the step's counters: the ranks' real atoms as collated (when
+the engine's host stats count them) and the backend compiles while the
+step ran.
 
 Elastic mid-run rescale
 -----------------------
@@ -53,6 +57,7 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.core.mace import MaceConfig, init_mace
 from repro.resilience.faults import FaultPlan
 from repro.resilience.heartbeat import (
@@ -477,11 +482,22 @@ class Trainer:
         self.fault_plan.slow_collate(process=proc)
         self.fault_plan.hang_at_step(self.global_step, process=proc)
         local = getattr(self.engine, "local_rank_range", range(len(rank_bins)))
-        mols_per_rank = [
-            [self.dataset.get(i) for i in b] if r in local else []
-            for r, b in enumerate(rank_bins)
-        ]
+        with tracing.span("data.materialise"):
+            mols_per_rank = [
+                [self.dataset.get(i) for i in b] if r in local else []
+                for r, b in enumerate(rank_bins)
+            ]
         return self.engine.collate(mols_per_rank, self.bin_shape)
+
+    @staticmethod
+    def _step_counters(host_stats, compiles_before: int) -> Dict[str, Any]:
+        """The ``train.step`` span's arguments: the backend compiles since
+        the step began and, where collation counted them, the ranks' real
+        atoms."""
+        counters = {"compiles": tracing.compile_count() - compiles_before}
+        if "atoms" in host_stats:
+            counters["atoms"] = host_stats["atoms"]
+        return counters
 
     def run_epoch(
         self,
@@ -533,43 +549,18 @@ class Trainer:
                 if self.watchdog is not None:
                     self.watchdog.arm(self.global_step)
                 try:
-                    for item in pipeline:
-                        batch, host_stats = item.batch
-                        # the step scalar must live on the engine's mesh too: a
-                        # jitted multi-process step rejects inputs committed to
-                        # a single local device (identity for the oracle)
-                        step_arr = self._place(jnp.asarray(self.global_step))
-                        self.params, self.opt_state, self.ef_state, metrics = (
-                            self.engine.step(
-                                self.params, self.opt_state, self.ef_state, batch,
-                                step_arr,
-                            )
-                        )
-                        self.ema_params = self.ema.update(
-                            self.ema_params, self.params, step_arr
-                        )
-                        self.global_step += 1
-                        self.sampler_state.cursor += 1
-                        self.engine.telemetry.record_host(
-                            item.collate_s, item.wait_s,
-                            host_stats.get("block_s", 0.0),
-                        )
-                        history.append({k: float(v) for k, v in metrics.items()})
-                        if self.heartbeat is not None:
-                            self.heartbeat.beat(
-                                self.global_step, self.sampler_state.epoch
-                            )
-                        if self.watchdog is not None:
-                            self.watchdog.check()
-                            self.watchdog.arm(self.global_step)
-
-                        if simulate_failure_at is not None and self.global_step >= simulate_failure_at:
-                            raise RuntimeError("simulated node failure")
-                        self.fault_plan.crash_at_step(
-                            self.global_step, process=self._process_index
-                        )
-                        if self.tcfg.ckpt_every and self.global_step % self.tcfg.ckpt_every == 0:
-                            self.save()
+                    while True:
+                        with tracing.span(
+                            "train.step", step_num=self.global_step
+                        ) as step:
+                            # counted only while a profiler records
+                            compiles = (tracing.compile_count()
+                                        if step.enabled else None)
+                            item = next(pipeline, None)  # train.wait_batch
+                            if item is None:
+                                break
+                            self._train_step(item, history, step, compiles,
+                                             simulate_failure_at)
                         if self.global_step in self.rescale_schedule:
                             break  # leave the with-block: drain, fire at loop top
                         if max_steps and self.global_step >= max_steps:
@@ -587,6 +578,47 @@ class Trainer:
                 return True
             if self.global_step not in self.rescale_schedule:
                 return False  # epoch stream exhausted, nothing pending
+
+    def _train_step(self, item, history, step,
+                    compiles_before: Optional[int],
+                    simulate_failure_at) -> None:
+        """One step on a prefetched batch, inside its ``train.step`` span."""
+        batch, host_stats = item.batch
+        # the step scalar must live on the engine's mesh too: a jitted
+        # multi-process step rejects inputs committed to a single local
+        # device (identity for the oracle)
+        step_arr = self._place(jnp.asarray(self.global_step))
+        self.params, self.opt_state, self.ef_state, metrics = self.engine.step(
+            self.params, self.opt_state, self.ef_state, batch, step_arr
+        )
+        with tracing.span("train.ema_update"):
+            self.ema_params = self.ema.update(
+                self.ema_params, self.params, step_arr
+            )
+        self.global_step += 1
+        self.sampler_state.cursor += 1
+        self.engine.telemetry.record_host(
+            item.collate_s, item.wait_s, host_stats.get("block_s", 0.0),
+        )
+        with tracing.span("train.metrics"):
+            history.append({k: float(v) for k, v in metrics.items()})
+        if compiles_before is not None:
+            step.annotate(
+                lambda: self._step_counters(host_stats, compiles_before))
+        if self.heartbeat is not None:
+            self.heartbeat.beat(self.global_step, self.sampler_state.epoch)
+        if self.watchdog is not None:
+            self.watchdog.check()
+            self.watchdog.arm(self.global_step)
+
+        if simulate_failure_at is not None and self.global_step >= simulate_failure_at:
+            raise RuntimeError("simulated node failure")
+        self.fault_plan.crash_at_step(
+            self.global_step, process=self._process_index
+        )
+        if self.tcfg.ckpt_every and self.global_step % self.tcfg.ckpt_every == 0:
+            with tracing.span("train.checkpoint"):
+                self.save()
 
     def train(
         self,

@@ -11,10 +11,18 @@ TPU, on the full-size bin's shapes (``launch.train.mace_cfm_run``): the
 grid matters too, since the compiler may place a small operand whole in
 VMEM, where it counts against the kernel's scoped budget.  Nothing runs.
 
+The model's loss, compiled the same way at a small bin with the named
+scopes in place, keeps the kernels' names in its Mosaic custom calls, so the
+benchmark's rooflines (``bench/benchlib/trace.kernel_name``) still find them.
+
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
 """
 import dataclasses
+import os
+import sys
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -35,6 +43,9 @@ from repro.kernels.symmetric_contraction.kernel import (
     symcon_bwd_pallas_raw,
     symcon_pallas_raw,
 )
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "bench"))
+from benchlib.trace import kernel_name  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -161,3 +172,31 @@ def test_symcon_bwd_compiles_for_v5e(one_chip):
         one_chip, a, w, g,
     )
     assert "tpu_custom_call" in text and "symcon_bwd" in text
+
+
+def test_loss_kernel_calls_keep_their_names_for_v5e(one_chip, monkeypatch):
+    """Forward and force pass of the model's loss under its named scopes:
+    every Mosaic call reduces to one of the four kernels' names."""
+    from repro.core.mace import init_mace, weighted_loss
+    from repro.data.collate import collate_bin
+    from repro.data.molecules import SyntheticCFMDataset
+
+    # the kernels compile for the chip instead of interpreting
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(CONFIG, impl="pallas", interaction_impl="pallas")
+    shape = BinShape.for_capacity(64, 16, 8, block_n=cfg.interaction_block_n)
+    ds = SyntheticCFMDataset(4, seed=0, max_atoms=16)
+    batch = collate_bin([ds.get(i) for i in range(4)], shape,
+                        with_blocking=True)
+    params = jax.eval_shape(lambda: init_mace(jax.random.PRNGKey(0), cfg))
+    sds = lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                         sharding=one_chip)
+    text = jax.jit(
+        lambda p, b: weighted_loss(p, cfg, b, shape.max_graphs, 0.0, 100.0)[0]
+    ).lower(jax.tree.map(sds, params), jax.tree.map(sds, batch)).compile(
+    ).as_text()
+    calls = [line.split(" = ")[0].split()[-1].lstrip("%")
+             for line in text.splitlines()
+             if "tpu_custom_call" in line and "custom-call(" in line]
+    assert {kernel_name(c) for c in calls} == {
+        "symcon_fwd", "symcon_bwd", "interaction_fwd", "interaction_bwd"}
