@@ -65,14 +65,15 @@ class InteractionSpec:
     block_n: int = 32
     # backward implementation for custom_vjp-carrying impls: "pallas" runs
     # the dedicated gather + TP-transpose backward kernel sharing the
-    # forward's tile geometry; "xla" retains the fused-XLA formulation's VJP
-    # (the capability fallback, and the second-order-autodiff escape hatch
-    # on compiled backends).  Impls without a custom backward ignore it.
+    # forward's tile geometry, and its own derivative (the second order)
+    # on the same two kernels; "xla" retains the fused-XLA formulation's
+    # VJP (the capability fallback).  Impls without a custom backward
+    # ignore it.
     bwd_impl: str = "pallas"
     # compute precision of the pallas kernels (fwd + hand-written bwd):
     # reduced precisions round operand tile loads, accumulation stays fp32
     # (repro.kernels.precision).  ref/fused impls ignore it (always fp32);
-    # the second-order XLA twins stay fp32 at every setting.
+    # the second-order pass stays fp32 at every setting.
     precision: str = "fp32"
 
     def __post_init__(self):

@@ -102,10 +102,10 @@ class MaceConfig:
     # and falls back to TP-kernel + segment_sum when absent.
     interaction_impl: str = "auto"
     # backward impl for custom-VJP interaction kernels: "pallas" = the
-    # dedicated blocked-gather + TP-transpose backward kernel (default),
-    # "xla" = the fused-XLA formulation's VJP (capability fallback; also
-    # the grad-of-grad escape hatch on compiled backends).  Ignored by
-    # impls without a hand-written backward.
+    # dedicated blocked-gather + TP-transpose backward kernel (default;
+    # grad-of-grad runs on the same kernels), "xla" = the fused-XLA
+    # formulation's VJP (capability fallback).  Ignored by impls without a
+    # hand-written backward.
     interaction_bwd_impl: str = "pallas"
     # atom rows per kernel tile; must match BinShape.block_n when blocking
     # metadata is consumed (data.blocking.DEFAULT_BLOCK_N)

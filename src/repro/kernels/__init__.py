@@ -13,7 +13,7 @@ additional backends (.cu, Triton, ...) should register themselves via
 metadata): *operand tile loads* are rounded through the reduced dtype
 (``precision.round_to``) while every accumulation — CG path sums, scatter
 adds, the hand-written backward's cotangent reductions — stays fp32, and
-the second-order XLA twins stay fp32 at every setting.  fp8 is *emulated*
+every second-order pass stays fp32 at every setting.  fp8 is *emulated*
 (e4m3 rounding of fp32 operands), an accuracy contract rather than a wire
 format.  Grad parity vs the fp32 ref oracle is bounded per precision by
 the L2 norm-relative tolerances in ``tests/test_precision.py``

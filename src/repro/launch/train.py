@@ -32,10 +32,11 @@ import argparse
 import os
 
 # Bin capacity (atoms) of the full-size MACE CFM run: the largest whose
-# training step, compiled for a v5e chip, peaks within 95% of the 15.75 GB
-# the chip offers (XLA memory analysis: 11.5 GB at 1024, 14.05 GB at 1280,
-# 14.87 GB at 1408, 15.51 GB at 1472).  The forces-in-loss second-order
-# backward holds the [E, k, d_out] temporaries that grow with it.
+# training step, compiled for a v5e chip, peaked within 95% of the 15.75 GB
+# the chip offers while the interaction's second order ran as XLA (memory
+# analysis: 11.5 GB at 1024, 14.05 GB at 1280, 14.87 GB at 1408, 15.51 GB
+# at 1472; its [E, k, nnz] temporaries grew with the bin).  On the kernels,
+# the per-bin gradient at 1408 peaks at about 4 GB, so the bin can grow.
 FULL_CAPACITY = 1408
 REDUCED_CAPACITY = 256
 

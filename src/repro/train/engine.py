@@ -107,9 +107,8 @@ per-edge ``[E, k, d_out]`` message tensor or any blocked copy (the backward
 re-gathers blocked operands from the residuals exactly like the forward
 does).  ``MaceConfig.interaction_bwd_impl`` / ``TrainerConfig.
 interaction_bwd_impl`` select ``"pallas"`` (the dedicated backward kernel,
-default) or ``"xla"`` (the fused formulation's VJP — the fallback for
-capability-gated platforms and for second-order autodiff on compiled
-backends).
+default; the second order runs on the same kernels) or ``"xla"`` (the
+fused formulation's VJP — the fallback for capability-gated platforms).
 
 Telemetry
 ---------

@@ -2,10 +2,12 @@
 mode on CPU) against the ref impls' autodiff — ``jax.grad`` parity for the
 symmetric contraction (dA + dW through the species gather) and the fused
 interaction (dY/dh/dR through blocked gather + TP-transpose), under padded
-atoms, masked edges, empty bins, and hub-spill blockings; a hypothesis
-property over random specs; the registry's backward capability metadata and
-the missing-backward differentiation guard; and a slow-marked bwd
-speed-regression guard mirroring the forward one.
+atoms, masked edges, empty bins, and hub-spill blockings; grad-of-grad
+parity for the interaction backward's own second-order rule (the same
+kernels at swapped operands); a hypothesis property over random specs; the
+registry's backward capability metadata and the missing-backward
+differentiation guard; and a slow-marked bwd speed-regression guard
+mirroring the forward one.
 """
 import dataclasses
 import time
@@ -221,6 +223,17 @@ def test_interaction_bwd_hub_spill_blocking():
     """A hub receiver whose degree exceeds the tile edge budget spills into
     extra virtual tiles sharing one base; the backward's tile-row gather
     must hand every spill tile the same cotangent row."""
+    args = _hub_spill_inputs()
+    blocking, b = _blocking_arrays(args[4], args[5], 16)
+    assert (np.asarray(b.tile_base) == 0).sum() == 3  # real hub spill
+    _assert_tree_allclose(
+        _interaction_grads(ISPEC, blocking, args), _ref_grads(args)
+    )
+
+
+def _hub_spill_inputs():
+    """A hub receiver (atom 3, 48 edges) whose degree exceeds the tile edge
+    budget, so it spills into extra virtual tiles sharing one base."""
     E, n_atoms, k = 64, 16, 4
     Y, h, R, senders, _, _ = _interaction_inputs(
         jax.random.PRNGKey(7), E, n_atoms, k
@@ -228,13 +241,70 @@ def test_interaction_bwd_hub_spill_blocking():
     receivers = jnp.concatenate(
         [jnp.full((48,), 3, jnp.int32), jnp.full((16,), 11, jnp.int32)]
     )
-    edge_mask = jnp.ones((E,), bool)
-    args = (Y, h, R, senders, receivers, edge_mask)
-    blocking, b = _blocking_arrays(receivers, edge_mask, n_atoms)
-    assert (np.asarray(b.tile_base) == 0).sum() == 3  # real hub spill
-    _assert_tree_allclose(
-        _interaction_grads(ISPEC, blocking, args), _ref_grads(args)
+    return Y, h, R, senders, receivers, jnp.ones((E,), bool)
+
+
+def _second_order_grads(op, args, g, cts):
+    """Grad-of-grad through ``op``'s backward: the gradient in
+    ``(g, Y, h, R)`` of ``<(a, b, c), vjp_op(g)>``, with cotangents
+    ``(a, b, c)`` on ``(dY, dh, dR)``."""
+    Y, h, R, senders, receivers, edge_mask = args
+
+    def second(gg, y, hh, r):
+        _, vjp = jax.vjp(
+            lambda y, hh, r: op(y, hh, r, senders, receivers, edge_mask),
+            y, hh, r,
+        )
+        return sum(jnp.sum(c * d) for c, d in zip(cts, vjp(gg)))
+
+    return jax.jit(jax.grad(second, argnums=(0, 1, 2, 3)))(g, Y, h, R)
+
+
+@pytest.mark.parametrize("case", [
+    "masked_padded",
+    pytest.param("hub_spill", marks=pytest.mark.slow),
+    pytest.param("empty_bin", marks=pytest.mark.slow),
+    pytest.param("unblocked", marks=pytest.mark.slow),
+])
+def test_interaction_second_order_grad_parity(case):
+    """The backward op's own derivative rule (three forward and three
+    backward kernel calls) against grad-of-grad through the ref impl, with
+    the cotangents on all of ``dY``, ``dh``, ``dR`` nonzero: on padded atoms
+    and masked edges, a hub-spill blocking, an empty bin (exact zeros), and
+    the unblocked capability fallback."""
+    if case == "hub_spill":
+        args, n_atoms = _hub_spill_inputs(), 16
+    elif case == "empty_bin":
+        args, n_atoms = _interaction_inputs(
+            jax.random.PRNGKey(6), 32, 9, 4, edge_keep=0.0
+        ), 9
+    else:
+        args, n_atoms = _interaction_inputs(jax.random.PRNGKey(5), 48, 21, 4), 21
+    blocking, b = _blocking_arrays(args[4], args[5], n_atoms)
+    if case == "hub_spill":
+        assert (np.asarray(b.tile_base) == 0).sum() == 3  # real hub spill
+    if case == "unblocked":
+        blocking = None
+    Y, h, R = args[:3]
+    k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(8), 4)
+    g = jax.random.normal(k1, (n_atoms,) + h.shape[1:-1]
+                          + (ISPEC.tp.out_spec.dim,), jnp.float32)
+    cts = [jax.random.normal(k, x.shape, jnp.float32)
+           for k, x in zip((k2, k3, k4), (Y, h, R))]
+
+    got = _second_order_grads(
+        lambda *a: interaction_pallas_op(
+            *a, spec=ISPEC, blocking=blocking, interpret=True
+        ),
+        args, g, cts,
     )
+    want = _second_order_grads(
+        lambda *a: interaction_reference(*a, ISPEC), args, g, cts
+    )
+    _assert_tree_allclose(got, want)
+    if case == "empty_bin":
+        for x in got:
+            np.testing.assert_array_equal(np.asarray(x), np.zeros_like(x))
 
 
 @pytest.mark.slow

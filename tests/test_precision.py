@@ -2,8 +2,10 @@
 
 The bf16/fp8 pallas variants round *operand tile loads* to the reduced
 dtype and accumulate in fp32 (``repro.kernels.precision.round_to``); the
-hand-written backward kernels apply the same rounding, and the
-second-order XLA twins stay fp32 at every setting.
+hand-written backward kernels apply the same rounding.  Every second-order
+pass stays fp32 at every setting: the interaction backward's own rule runs
+its forward and backward kernels at fp32 (pinned bit for bit below), and
+the symcon and TP-only backwards differentiate their fp32 XLA twins.
 
 Tolerance contract (PRECISION_TOL): gradients and outputs are compared to
 the fp32 ref oracle with the **L2 norm-relative** metric per tensor,
@@ -244,6 +246,35 @@ def test_interaction_precision_hub_spill(precision, impl):
         _interaction_grads(ISPEC, "ref", None, args),
         precision,
     )
+
+
+@pytest.mark.parametrize("precision,impl", VARIANTS)
+def test_interaction_second_order_stays_fp32(precision, impl):
+    """The interaction backward's own derivative rule runs its kernels at
+    fp32 whatever the op's precision: grad-of-grad through the reduced-
+    precision op, taken in the first-order cotangent and the operands, is
+    the fp32 op's bit for bit."""
+    args = _interaction_inputs(jax.random.PRNGKey(5), 48, 21, 4)
+    blocking, _ = _blocking_arrays(args[4], args[5], 21)
+    k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(6), 4)
+    g = jax.random.normal(k1, (21, 4, ISPEC.tp.out_spec.dim), jnp.float32)
+    cts = [jax.random.normal(k, x.shape, jnp.float32)
+           for k, x in zip((k2, k3, k4), args[:3])]
+
+    def second_order(name):
+        fn = registry.resolve("interaction", name, ISPEC)
+
+        def inner(gg, y, hh, r):
+            _, vjp = jax.vjp(
+                lambda y, hh, r: fn(y, hh, r, *args[3:], blocking=blocking),
+                y, hh, r,
+            )
+            return sum(jnp.sum(c * d) for c, d in zip(cts, vjp(gg)))
+
+        return jax.jit(jax.grad(inner, argnums=(0, 1, 2, 3)))(g, *args[:3])
+
+    for got, want in zip(second_order(impl), second_order("pallas")):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # ---------------------------------------------------------------------------

@@ -14,12 +14,16 @@ VMEM, where it counts against the kernel's scoped budget.  Nothing runs.
 The model's loss, compiled the same way at a small bin with the named
 scopes in place, keeps the kernels' names in its Mosaic custom calls, so the
 benchmark's rooflines (``bench/benchlib/trace.kernel_name``) still find them.
+So does its parameter gradient, whose second-order pass runs the
+interaction on its own kernels and holds no per-edge ``[E, k, nnz]``
+CG-contribution tensor.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
 """
 import dataclasses
 import os
+import re
 import sys
 
 import numpy as np
@@ -174,9 +178,11 @@ def test_symcon_bwd_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in text and "symcon_bwd" in text
 
 
-def test_loss_kernel_calls_keep_their_names_for_v5e(one_chip, monkeypatch):
-    """Forward and force pass of the model's loss under its named scopes:
-    every Mosaic call reduces to one of the four kernels' names."""
+def _compile_small_loss(one_chip, monkeypatch, grad: bool):
+    """The model's forces-in-loss objective on a small bin at full widths,
+    all-Pallas, compiled for the chip: its value (forward and force pass)
+    or its parameter gradient (adding the second-order pass).  Returns the
+    HLO text, the bin and the config."""
     from repro.core.mace import init_mace, weighted_loss
     from repro.data.collate import collate_bin
     from repro.data.molecules import SyntheticCFMDataset
@@ -191,12 +197,48 @@ def test_loss_kernel_calls_keep_their_names_for_v5e(one_chip, monkeypatch):
     params = jax.eval_shape(lambda: init_mace(jax.random.PRNGKey(0), cfg))
     sds = lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
                                          sharding=one_chip)
-    text = jax.jit(
-        lambda p, b: weighted_loss(p, cfg, b, shape.max_graphs, 0.0, 100.0)[0]
-    ).lower(jax.tree.map(sds, params), jax.tree.map(sds, batch)).compile(
-    ).as_text()
+
+    def loss(p, b):
+        return weighted_loss(p, cfg, b, shape.max_graphs, 0.0, 100.0)[0]
+
+    text = jax.jit(jax.grad(loss) if grad else loss).lower(
+        jax.tree.map(sds, params), jax.tree.map(sds, batch)
+    ).compile().as_text()
+    return text, shape, cfg
+
+
+def _kernel_names(text):
     calls = [line.split(" = ")[0].split()[-1].lstrip("%")
              for line in text.splitlines()
              if "tpu_custom_call" in line and "custom-call(" in line]
-    assert {kernel_name(c) for c in calls} == {
+    return {kernel_name(c) for c in calls}
+
+
+def test_loss_kernel_calls_keep_their_names_for_v5e(one_chip, monkeypatch):
+    """Forward and force pass of the model's loss under its named scopes:
+    every Mosaic call reduces to one of the four kernels' names."""
+    text, _, _ = _compile_small_loss(one_chip, monkeypatch, grad=False)
+    assert _kernel_names(text) == {
         "symcon_fwd", "symcon_bwd", "interaction_fwd", "interaction_bwd"}
+
+
+def test_loss_param_grad_second_order_on_kernels_for_v5e(one_chip,
+                                                         monkeypatch):
+    """The parameter gradient of the forces-in-loss objective, the
+    second-order pass included: the interaction's second order runs on its
+    own kernels, so no per-edge CG-contribution tensor ``f32[E, k, nnz]``
+    (the XLA twin's largest temporary, in any axis order) is left, and
+    every Mosaic call still reduces to the four kernels' names."""
+    text, shape, cfg = _compile_small_loss(one_chip, monkeypatch, grad=True)
+    assert _kernel_names(text) == {
+        "symcon_fwd", "symcon_bwd", "interaction_fwd", "interaction_bwd"}
+    contrib = {
+        tuple(sorted((shape.max_edges, cfg.channels, len(build_tp_tables(
+            cfg.interaction_spec_at(layer).tp).val))))
+        for layer in range(cfg.n_interactions)
+    }
+    f32_shapes = {
+        tuple(sorted(int(d) for d in dims.split(",")))
+        for dims in re.findall(r"\bf32\[([0-9,]+)\]", text)
+    }
+    assert not contrib & f32_shapes, contrib & f32_shapes
