@@ -23,7 +23,7 @@ import importlib
 import math
 import sys
 import time
-from typing import Dict, List
+from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
@@ -54,6 +54,7 @@ class StepLog(list):
         self.deadline = deadline
         self.stamps: List[float] = []
         self.bins: List[List[List[int]]] = []
+        self.positions: List[Tuple[int, int]] = []  # (step of epoch, of n)
 
     def append(self, metrics):
         now = time.perf_counter()
@@ -65,6 +66,7 @@ class StepLog(list):
         r = self.trainer.engine.n_ranks
         all_bins = self.trainer.sampler.bins_for_epoch(st.epoch)
         self.bins.append(all_bins[(st.cursor - 1) * r: st.cursor * r])
+        self.positions.append((st.cursor - 1, len(all_bins) // r))
 
 
 def train(tr, log: StepLog, max_steps=None) -> None:
@@ -242,6 +244,28 @@ def watched():
               f"window took {sum(collections):.3f} s", file=sys.stderr)
 
 
+def stalls(ms: Sequence[float]) -> Dict:
+    """The window's stalls, from each step's wall ms: the median step, the
+    steps longer than twice it (how many, and their summed time over the
+    median) and the longest step (its index in the window and its ms)."""
+    med = float(np.median(ms))
+    over = [m - med for m in ms if m > 2 * med]
+    longest = int(np.argmax(ms))
+    return {"median_ms": med, "count": len(over), "excess_ms": float(sum(over)),
+            "longest": longest, "longest_ms": float(ms[longest])}
+
+
+def stall_note(ms: Sequence[float], positions: Sequence[Tuple[int, int]]) -> str:
+    """One line for standard error: ``stalls(ms)`` and where in its epoch
+    the longest step fell."""
+    s = stalls(ms)
+    at, of = positions[s["longest"]]
+    return (f"note: stalls: median step {s['median_ms']:.2f} ms; {s['count']} "
+            f"steps over twice it, {s['excess_ms']:.1f} ms over the median in "
+            f"all; longest step {s['longest']} of {len(ms)} "
+            f"({s['longest_ms']:.1f} ms, step {at} of {of} in its epoch)")
+
+
 def run(r) -> Dict:
     """One run of a training cell; ``r`` is the harness's run context."""
     marks = [("start", r.t_process)]
@@ -279,6 +303,7 @@ def run(r) -> Dict:
     mark("window", t_end)
     steps = _steps(pool, window)
     steps[0]["ms"] = (window.stamps[0] - t_start) * 1e3
+    print(stall_note([s["ms"] for s in steps], window.positions), file=sys.stderr)
     memory_peak = program.peak_bytes(r.devices[0])
     mark("memory")
     setup_s = t_start - r.t_process
